@@ -4,8 +4,8 @@ Models expose ``quant_site_map() -> {param_path: site_key}`` where each
 mapped leaf has shape ``(L, [extra...], n_in, n_out)`` (layer-stacked for
 scan; MoE adds an experts dim) and ``stats[site_key]["mean_abs"]`` is
 ``(L, n_in)``.  Because all per-layer weights are stacked, whole-model
-quantization is a few ``vmap`` calls — and trivially layer-parallel in the
-distributed path.
+quantization is one ``lax.map`` over layers per site — and trivially
+layer-parallel in the distributed path.
 
 Two output modes:
 
@@ -18,6 +18,7 @@ Two output modes:
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -50,52 +51,57 @@ def _quantize_leaf(w, stat, spec, alpha_grid, loss, stats_site, mode):
     ``stat`` is the (L, n_in) method statistic or None (RTN).
     Returns (new_leaf, report_dict).
     """
+    if mode not in ("fake", "packed"):
+        raise ValueError(f"unknown mode {mode!r}")
+    mean_sq = sample = None
+    if stat is not None:
+        mean_sq = stats_site["mean_sq"] if loss == "diag" else None
+        sample = stats_site["sample"] if loss == "sample" else None
+    return _quantize_layers(w, stat, mean_sq, sample, spec=spec,
+                            alpha_grid=tuple(alpha_grid), mode=mode)
+
+
+@partial(jax.jit, static_argnames=("spec", "alpha_grid", "mode"))
+def _quantize_layers(w, stat, mean_sq, sample, *, spec, alpha_grid, mode):
+    """α search and quantization of an (L, [extra...], n_in, n_out) stack,
+    one layer at a time (``lax.map``): only one layer's f32 temporaries
+    are live, so the peak stays that of a layer however deep the model
+    is.  The reshapes stay inside the program, where they copy nothing."""
     L = w.shape[0]
     n_in, n_out = w.shape[-2], w.shape[-1]
     extra = w.shape[1:-2]
+
+    def one_layer(xs):
+        w_l, stat_l, msq_l, smp_l = xs                # w_l (E, n_in, n_out)
+        if stat_l is None:                            # RTN
+            act, report = None, {}
+        else:
+            res = jax.vmap(lambda w2: search_alpha(
+                w2, stat_l, spec, alpha_grid, mean_sq=msq_l,
+                sample=smp_l))(w_l)
+            act = res.act_scale                       # (E, n_in)
+            report = {"alpha": res.alpha, "loss": res.loss,
+                      "rtn_loss": res.rtn_loss}
+        if mode == "fake":
+            if act is None:
+                out = jax.vmap(lambda x: quant_dequant(x, spec))(w_l)
+            else:
+                out = jax.vmap(lambda x, s: quant_dequant(
+                    x, spec, act_scale=s))(w_l, act)
+            out = out.astype(w_l.dtype)
+        elif act is None:
+            out = jax.vmap(lambda x: quantize_groupwise(
+                x, spec, pack=True))(w_l)
+        else:
+            out = jax.vmap(lambda x, s: quantize_groupwise(
+                x, spec, act_scale=s, pack=True))(w_l, act)
+        return out, report
+
     w_flat = w.reshape((L, -1, n_in, n_out))
-    E = w_flat.shape[1]
-
-    if stat is None:  # RTN
-        act_scale = None
-        report = {}
-    else:
-        mean_sq = stats_site["mean_sq"] if loss == "diag" else None
-        sample = stats_site["sample"] if loss == "sample" else None
-
-        def search_le(w2, a, msq, smp):
-            return search_alpha(w2, a, spec, alpha_grid, mean_sq=msq, sample=smp)
-
-        in_e = (0, None, None, None)
-        in_l = (0, 0,
-                0 if mean_sq is not None else None,
-                0 if sample is not None else None)
-        res = jax.vmap(jax.vmap(search_le, in_axes=in_e), in_axes=in_l)(
-            w_flat, stat, mean_sq, sample)
-        act_scale = res.act_scale  # (L, E, n_in)
-        report = {"alpha": res.alpha, "loss": res.loss, "rtn_loss": res.rtn_loss}
-
-    if mode == "fake":
-        if act_scale is None:
-            qd = jax.vmap(jax.vmap(lambda x: quant_dequant(x, spec)))(w_flat)
-        else:
-            qd = jax.vmap(jax.vmap(lambda x, s: quant_dequant(x, spec, act_scale=s)))(
-                w_flat, act_scale)
-        new_leaf = qd.reshape(w.shape).astype(w.dtype)
-    elif mode == "packed":
-        if act_scale is None:
-            qt = jax.vmap(jax.vmap(
-                lambda x: quantize_groupwise(x, spec, pack=True)))(w_flat)
-        else:
-            qt = jax.vmap(jax.vmap(
-                lambda x, s: quantize_groupwise(x, spec, act_scale=s, pack=True)))(
-                w_flat, act_scale)
-        # reshape batched QuantizedTensor leaves back to (L, *extra, ...)
-        qt = jax.tree_util.tree_map(
-            lambda a: a.reshape((L,) + extra + a.shape[2:]), qt)
-        new_leaf = qt
-    else:
-        raise ValueError(f"unknown mode {mode!r}")
+    new_leaf, report = jax.lax.map(one_layer, (w_flat, stat, mean_sq, sample))
+    # back from (L, E, ...) to (L, *extra, ...)
+    new_leaf = jax.tree_util.tree_map(
+        lambda a: a.reshape((L,) + extra + a.shape[2:]), new_leaf)
     return new_leaf, report
 
 

@@ -33,6 +33,12 @@ class DataConfig:
 
 
 class SyntheticLM:
+    # Budget for memoised cumulative transition rows: every row at the
+    # small test vocabularies, a few dozen at a 128k vocab (1 MB a row).
+    ROW_MEMO_BYTES = 64 << 20
+    # perplexity_upper_bound builds the whole (v, v) matrix
+    PPL_MAX_VOCAB = 8192
+
     def __init__(self, cfg: DataConfig):
         self.cfg = cfg
         rng = np.random.Generator(np.random.PCG64(cfg.seed))
@@ -40,16 +46,36 @@ class SyntheticLM:
         # Zipf-ish unigram prior
         prior = 1.0 / np.arange(1, v + 1) ** cfg.zipf_a
         prior /= prior.sum()
+        self.prior = prior
         self.prior_cum = np.cumsum(prior)
-        # per-token successor sets with random weights
-        succ = rng.integers(0, v, size=(v, cfg.branching))
-        w = rng.dirichlet(np.ones(cfg.branching) * 0.5, size=v)
-        trans = np.zeros((v, v), np.float64)
-        rows = np.repeat(np.arange(v), cfg.branching)
-        trans[rows, succ.reshape(-1)] += w.reshape(-1)
-        trans += 1e-3 * prior[None, :]     # smoothing mass
-        trans /= trans.sum(axis=1, keepdims=True)
-        self.trans_cum = np.cumsum(trans, axis=1)
+        # per-token successor sets with random weights; each token's
+        # transition row is built from these on demand, so memory grows
+        # with v * branching rather than v**2
+        self.succ = rng.integers(0, v, size=(v, cfg.branching))
+        self.succ_w = rng.dirichlet(np.ones(cfg.branching) * 0.5, size=v)
+        self._rows: dict = {}
+        self._memo_rows = max(1, self.ROW_MEMO_BYTES // (8 * v))
+
+    def _build_cum_row(self, tok: int) -> np.ndarray:
+        """Cumulative transition distribution out of ``tok``: the same
+        numpy operations, in the same order, as one row of the dense
+        ``(v, v)`` matrix — fancy-index ``+=`` (a repeated successor
+        keeps its last weight), ``1e-3 * prior`` smoothing, row
+        normalisation, cumsum — so every stream stays bit-identical."""
+        row = np.zeros(self.cfg.vocab_size, np.float64)
+        row[self.succ[tok]] += self.succ_w[tok]
+        row += 1e-3 * self.prior           # smoothing mass
+        row /= row.sum()
+        return np.cumsum(row)
+
+    def cum_row(self, tok: int) -> np.ndarray:
+        """Memoised :meth:`_build_cum_row` (oldest row evicted first)."""
+        row = self._rows.get(tok)
+        if row is None:
+            if len(self._rows) >= self._memo_rows:
+                del self._rows[next(iter(self._rows))]
+            row = self._rows[tok] = self._build_cum_row(tok)
+        return row
 
     def sequence(self, index: int, length: int,
                  first_token_range: Optional[Tuple[int, int]] = None
@@ -65,7 +91,8 @@ class SyntheticLM:
             out[0] = np.searchsorted(self.prior_cum, rng.random())
         u = rng.random(length - 1)
         for t in range(1, length):
-            out[t] = np.searchsorted(self.trans_cum[out[t - 1]], u[t - 1])
+            out[t] = np.searchsorted(self.cum_row(int(out[t - 1])),
+                                     u[t - 1])
         return out
 
     def batch(self, step: int, batch_size: int, length: int,
@@ -84,10 +111,17 @@ class SyntheticLM:
 
     def perplexity_upper_bound(self) -> float:
         """Entropy of the true process (nats) -> the floor a perfect model
-        can reach; useful to sanity-check training."""
+        can reach; useful to sanity-check training.  Builds every row, so
+        only for vocabularies up to ``PPL_MAX_VOCAB``."""
+        v = self.cfg.vocab_size
+        if v > self.PPL_MAX_VOCAB:
+            raise ValueError(f"perplexity_upper_bound builds all {v} rows; "
+                             f"only vocabularies <= {self.PPL_MAX_VOCAB} "
+                             f"are allowed")
         # H(next | prev) under the stationary-ish prior
-        trans = np.diff(np.concatenate([np.zeros((self.cfg.vocab_size, 1)),
-                                        self.trans_cum], axis=1), axis=1)
+        cum = np.stack([self._build_cum_row(i) for i in range(v)])
+        trans = np.diff(np.concatenate([np.zeros((v, 1)), cum], axis=1),
+                        axis=1)
         prior = np.diff(np.concatenate([[0.0], self.prior_cum]))
         h = -np.sum(prior[:, None] * trans * np.log(np.maximum(trans, 1e-12)))
         return float(np.exp(h))

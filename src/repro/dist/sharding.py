@@ -125,15 +125,25 @@ def row_parallel():
     contradicts the placement chosen from ``param_axes()`` and would
     insert a per-layer weight reshard.  Re-binding ``qin`` to ``model``
     inside this context disarms the branch (the rule is no longer None)
-    and matches the actual row layout.  No-op without an active mesh or
-    when ``qin`` is already bound.
+    and matches the actual row layout.  The kernel dispatch reads the
+    mark itself through :func:`in_row_parallel` to pick its shard_map
+    layout.
     """
-    mesh = active_mesh()
-    if mesh is None or active_rule("qin") is not None:
-        yield
-        return
-    with axis_rules(mesh, dict(active_rules(), qin="model")):
-        yield
+    _CTX.row = getattr(_CTX, "row", 0) + 1
+    try:
+        mesh = active_mesh()
+        if mesh is None or active_rule("qin") is not None:
+            yield
+        else:
+            with axis_rules(mesh, dict(active_rules(), qin="model")):
+                yield
+    finally:
+        _CTX.row -= 1
+
+
+def in_row_parallel() -> bool:
+    """True inside a :func:`row_parallel` region."""
+    return getattr(_CTX, "row", 0) > 0
 
 
 # ---------------------------------------------------------------------------

@@ -23,8 +23,8 @@ kernels fix both:
   sharding rationale in the jnp oracle (repeating KV to q-heads forces
   an SPMD reshard that replicates the cache in f32).
 * **int8 fold** (`*_q8`).  The per-(token, head) scales multiply the
-  score matrix / probability weights inside the kernel, so int8 codes
-  are consumed in their packed domain and never hit HBM as f32.
+  K/V rows inside the kernel (so the scores and the prob-weighted V sum
+  carry them), and int8 codes never hit HBM as f32.
 * **In-kernel page gather** (`*_paged*`).  The page table is
   scalar-prefetched and the K/V index_maps read physical pages straight
   out of the shared page store — the dense-HBM ``gather_pages``
@@ -43,8 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -70,32 +68,39 @@ def _decode_body(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     if window is not None:
         run = jnp.logical_and(run, start + bs > length - window)
 
+    def live_mask(shape, axis):
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        mask = pos < length
+        if window is not None:
+            mask = jnp.logical_and(mask, pos >= length - window)
+        return mask
+
     @pl.when(run)
     def _live():
         q = q_ref[0, 0].astype(jnp.float32)               # (G, hd)
         k = k_ref[0, 0].astype(jnp.float32)               # (bs, hd)
         v = v_ref[0, 0].astype(jnp.float32)
-        sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        # int8 fold: the per-(token, head) scales are (bs, 1) columns, so
+        # they multiply the K/V rows (the scores and the prob-weighted V
+        # sum then carry them); Mosaic cannot transpose them to (1, bs)
         if ks_ref is not None:
-            # int8 fold: per-(token, head) K scale into the score row
-            sc = sc * jnp.transpose(ks_ref[0, 0])         # (1, bs)
-        kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        mask = kpos < length
-        if window is not None:
-            mask = jnp.logical_and(mask, kpos >= length - window)
+            k = k * ks_ref[0, 0]
+        if vs_ref is not None:
+            v = v * vs_ref[0, 0]
+        sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        mask = live_mask((1, bs), 1)                      # score columns
         sc = jnp.where(mask, sc, NEG_INF)
         m = jnp.max(sc, axis=-1, keepdims=True)           # (G, 1)
         p = jnp.exp(sc - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
-        if vs_ref is not None:
-            # int8 fold: per-(token, head) V scale into the prob weights
-            p = p * jnp.transpose(vs_ref[0, 0])
         # hard-zero masked prob columns and V rows: a partial last
         # block's out-of-bounds K/V region is undefined (NaN-filled in
         # interpret mode), and IEEE 0 * NaN = NaN would otherwise leak
-        # through the V dot even though exp(-1e30 - m) underflows to 0
+        # through the V dot even though exp(-1e30 - m) underflows to 0.
+        # The V-row mask is built in (bs, 1) directly: Mosaic cannot
+        # transpose a boolean vector.
         p = jnp.where(mask, p, 0.0)
-        v = jnp.where(jnp.transpose(mask), v, 0.0)
+        v = jnp.where(live_mask((bs, 1), 0), v, 0.0)
         o_ref[0, 0, 0] = jnp.dot(p, v, preferred_element_type=jnp.float32)
         m_ref[0, 0, 0] = m
         l_ref[0, 0, 0] = l
@@ -204,7 +209,7 @@ def _out_shapes(b, kh, ns, g, hd):
             jax.ShapeDtypeStruct((b, kh, ns, g, 1), jnp.float32)]
 
 
-_SEMANTICS = _CompilerParams(
+_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 

@@ -12,12 +12,11 @@ import os
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.quantizer import QuantizedTensor
-from repro.dist.sharding import (active_mesh, active_rule, logical_to_spec,
-                                 shard_hint)
+from repro.dist.sharding import (active_mesh, active_rule, in_row_parallel,
+                                 logical_to_spec, shard_hint)
 from . import ref as ref_ops
 from .flash_decode import (flash_decode_paged_pallas,
                            flash_decode_paged_q8_pallas,
@@ -60,9 +59,51 @@ def quant_matmul(x: jax.Array, qt: QuantizedTensor) -> jax.Array:
     # unchanged — the old pad-rows-to-min(128, m) here became redundant
     # (and it never covered the dimension that actually crashed: n_out
     # not a multiple of the 128 tile, e.g. hymba's d_model=1600).
-    out = quant_matmul_pallas(x2, qt.codes, qt.scale, qt.zero,
-                              interpret=(mode != "tpu"))
+    kernel = functools.partial(quant_matmul_pallas,
+                               interpret=(mode != "tpu"))
+    mesh = _model_mesh()
+    if mesh is not None:
+        kernel = _quant_matmul_shard_map(kernel, mesh, x2, qt)
+    out = kernel(x2, qt.codes, qt.scale, qt.zero)
     return out.reshape(lead + (qt.codes.shape[-1],)).astype(x.dtype)
+
+
+def _model_mesh():
+    """The active mesh iff it has a "model" axis of size > 1."""
+    mesh = active_mesh()
+    if (isinstance(mesh, jax.sharding.Mesh)
+            and dict(mesh.shape).get("model", 1) > 1):
+        return mesh
+    return None
+
+
+def _quant_matmul_shard_map(kernel, mesh, x2, qt):
+    """Run the dequant-matmul kernel per device: Mosaic kernels cannot be
+    partitioned by GSPMD.  Column-parallel sites split the output
+    columns over "model"; row-parallel sites (:func:`row_parallel`:
+    ``wo``, ``w_down``) split the input channels — whole quant groups per
+    device — and all-reduce the partial products.  A site whose dims do
+    not divide runs replicated."""
+    m = dict(mesh.shape)["model"]
+    n = qt.codes.shape[-1]
+    n_groups = qt.scale.shape[0]
+    rows = _batch_entry(x2.shape[0], mesh)
+    if in_row_parallel() and n_groups % m == 0:
+        w = P("model", None)
+        body = lambda *a: jax.lax.psum(kernel(*a), "model")
+        in_specs = (P(rows, "model"), w, w, w)
+        out_spec = P(rows, None)
+    elif n % m == 0:
+        w = P(None, "model")
+        body = kernel
+        in_specs = (P(rows, None), w, w, w)
+        out_spec = P(rows, "model")
+    else:
+        body = kernel
+        in_specs = (P(rows, None), P(), P(), P())
+        out_spec = P(rows, None)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_spec, check_vma=False)
 
 
 def quant_error_batch(w: jax.Array, scales: jax.Array, mean_sq: jax.Array,
@@ -127,13 +168,11 @@ def quant_matmul_experts(x: jax.Array, qt: QuantizedTensor) -> jax.Array:
 
 def _tp_mesh(n_q_heads: int, n_kv_heads: int):
     """The active mesh iff head-axis shard_map is applicable, else None."""
-    mesh = active_mesh()
-    if not isinstance(mesh, jax.sharding.Mesh):
+    mesh = _model_mesh()
+    if mesh is None:
         return None
-    m = dict(mesh.shape).get("model", 1)
-    if m <= 1 or n_q_heads % m or n_kv_heads % m:
-        return None
-    return mesh
+    m = dict(mesh.shape)["model"]
+    return None if n_q_heads % m or n_kv_heads % m else mesh
 
 
 def _batch_entry(n: int, mesh):
@@ -194,8 +233,8 @@ def _dense_shard_map(body, mesh, q, n_kv: int):
     kvspec = P(b, "model", None, None)
     n_caches = n_kv  # cache-layout operands between q and cache_len
     in_specs = (qspec,) + (kvspec,) * n_caches + (P(b),)
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=qspec,
-                     check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=qspec, check_vma=False)
 
 
 def _paged_shard_map(body, mesh, q, n_stores: int):
@@ -207,8 +246,8 @@ def _paged_shard_map(body, mesh, q, n_stores: int):
     qspec = P(b, None, "model", None)
     store = P(None, "model", None, None)
     in_specs = (qspec,) + (store,) * n_stores + (P(b, None), P(b))
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=qspec,
-                     check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=qspec, check_vma=False)
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 from repro.core.quantizer import QuantSpec
 
 
@@ -100,7 +98,7 @@ def quant_error_pallas(w: jax.Array, scales: jax.Array, mean_sq: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, 1), lambda aa, kk, j: (aa, 0)),
         out_shape=jax.ShapeDtypeStruct((a, 1), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary",                                              "arbitrary")),
         interpret=interpret,
     )(w, scales, msq2)

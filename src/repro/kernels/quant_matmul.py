@@ -3,16 +3,21 @@
 The serving hot-spot of the FAQ/AWQ deployment format.  Int4 weight codes
 are packed two-per-byte in HBM; each grid step stages a ``(bk/2, bn)``
 packed block plus its per-group scales/zeros into VMEM, dequantizes
-in-register, and feeds the MXU with a ``(bm, bk) @ (bk, bn)`` matmul,
-accumulating in f32 across the K grid axis.
+in-register, and feeds the MXU, accumulating in f32 across the K grid
+axis.
 
 TPU adaptation notes (vs. AWQ's CUDA dequant-GEMM):
-  * HBM->VMEM staging is expressed with BlockSpecs; the MXU dims (bm, bn)
-    are multiples of 128 and bk is a multiple of the quant group size so a
-    scale group never straddles K blocks.
-  * The nibble unpack is an interleave on the second-minor axis
-    (stack + reshape), which Mosaic lowers to vector ops; validated here
-    in interpret mode (this container is CPU-only).
+  * HBM->VMEM staging is expressed with BlockSpecs.  Mosaic needs the
+    last two dims of every block to be multiples of (8, 128) or the whole
+    array dim, so ``bk`` is a multiple of ``8 * g`` (eight scale rows per
+    block) and of 256 (the half-K activation blocks below are 128-lane
+    aligned), or else the whole K axis.  A scale group never straddles K
+    blocks.
+  * Packed byte ``i`` holds input channels ``2i`` (low nibble) and
+    ``2i + 1`` (high nibble).  Instead of interleaving the two nibble
+    planes back into K order in VMEM, the wrapper splits the activation
+    into its even and odd input channels, and the kernel computes
+    ``x_even @ deq(lo) + x_odd @ deq(hi)``.
   * The per-channel AWQ/FAQ smoothing scale is folded into the activation
     *outside* the kernel (one fused elementwise op), keeping the kernel a
     pure grouped-dequant GEMM.
@@ -20,59 +25,63 @@ TPU adaptation notes (vs. AWQ's CUDA dequant-GEMM):
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
 
-
-def _kernel(x_ref, codes_ref, scale_ref, zero_ref, out_ref, *, bk: int):
+def _kernel(xe_ref, xo_ref, codes_ref, scale_ref, zero_ref, out_ref):
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    codes = codes_ref[...]                    # (bk//2, bn) uint8
-    lo = (codes & jnp.uint8(0x0F)).astype(jnp.float32)
-    hi = ((codes >> 4) & jnp.uint8(0x0F)).astype(jnp.float32)
-    w = jnp.stack([lo, hi], axis=1).reshape(bk, codes.shape[-1])
-
+    # Mosaic casts uint8 to f32 only through a 32-bit integer
+    codes = codes_ref[...].astype(jnp.int32)  # (bk//2, bn) packed bytes
     scale = scale_ref[...]                    # (bk//g, bn)
     zero = zero_ref[...]
-    g = bk // scale.shape[0]
-    s_full = jnp.repeat(scale, g, axis=0)
-    z_full = jnp.repeat(zero, g, axis=0)
-    w = (w - z_full) * s_full                 # dequant in VMEM
+    n_groups, bn = scale.shape
+    rows = codes.shape[0] // n_groups         # packed rows per group (g/2)
 
-    x = x_ref[...].astype(jnp.float32)        # (bm, bk)
-    out_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    def dequant(plane):                       # (bk//2, bn) codes of one nibble
+        w = plane.astype(jnp.float32).reshape(n_groups, rows, bn)
+        return ((w - zero[:, None]) * scale[:, None]).reshape(-1, bn)
+
+    lo = dequant(codes & 0x0F)                # input channels 2i
+    hi = dequant(codes >> 4)                  # input channels 2i+1
+    xe = xe_ref[...].astype(jnp.float32)      # (bm, bk//2)
+    xo = xo_ref[...].astype(jnp.float32)
+    out_ref[...] += (jnp.dot(xe, lo, preferred_element_type=jnp.float32)
+                     + jnp.dot(xo, hi, preferred_element_type=jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def _k_block(k: int, g: int) -> int:
+    """K tile: a multiple of ``8 * g`` and of 256 when one divides ``k``
+    (the smallest such), else the whole K axis."""
+    step = math.lcm(8 * g, 256)
+    return step if k % step == 0 else k
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def quant_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
                         zero: jax.Array, *, bm: int = 128, bn: int = 128,
-                        bk: int = 128, interpret: bool = True) -> jax.Array:
+                        interpret: bool = True) -> jax.Array:
     """x: (m, k) float; codes: (k//2, n) packed uint8;
     scale/zero: (k//g, n) f32.  Returns (m, n) f32."""
     m, k = x.shape
     n = codes.shape[-1]
     n_groups = scale.shape[0]
     g = k // n_groups
+    assert g % 2 == 0, (  # repro: noqa[RPR007] packing invariant, not a tile-shape constraint
+        f"quant group size must be even to unpack nibble-packed codes "
+        f"(g={g})")
+    bk = _k_block(k, g)
     bm = min(bm, m)
     bn = min(bn, n)
-    bk = min(bk, k)
-    if bk % g != 0 or k % bk != 0:
-        bk = g  # never straddle a quant group across K blocks; the
-        #         group size always divides k, so this also covers
-        #         k not a multiple of the default tile
-    assert k % bk == 0, (k, bk, g)  # repro: noqa[RPR007] bk=g fallback above guarantees this
-    assert bk % 2 == 0, (  # repro: noqa[RPR007] packing invariant, not a tile-shape constraint
-        f"quant group size must be even to unpack nibble-packed codes "
-        f"in K blocks (bk={bk})")
     # m and n need not divide the MXU tile (hymba's d_model=1600 leaves
     # 1600 % 128 = 64): pad both up to the tile and slice the result.
     # Padded activation rows are zeros; padded weight columns carry
@@ -87,21 +96,23 @@ def quant_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
         scale = jnp.pad(scale, ((0, 0), (0, pad_n)))
         zero = jnp.pad(zero, ((0, 0), (0, pad_n)))
     mp, np_ = m + pad_m, n + pad_n
+    x_even, x_odd = x[:, 0::2], x[:, 1::2]
 
     grid = (mp // bm, np_ // bn, k // bk)
     out = pl.pallas_call(
-        functools.partial(_kernel, bk=bk),
+        _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bm, bk // 2), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bm, bk // 2), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((bk // g, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((bk // g, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel",                                              "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, codes, scale, zero)
+    )(x_even, x_odd, codes, scale, zero)
     return out[:m, :n]

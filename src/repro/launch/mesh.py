@@ -7,6 +7,15 @@ initialization).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with every axis in Auto mode: the serving and
+    training paths shard through GSPMD hints (``shard_hint``), which
+    explicit axes — ``make_mesh``'s default since JAX 0.7 — reject."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _take_devices(shape, what: str):
@@ -29,10 +38,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     devices = _take_devices(shape, "make_production_mesh")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return auto_mesh(shape, axes, devices)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over local devices (CPU tests of the sharded paths)."""
     devices = _take_devices((data, model), "make_local_mesh")
-    return jax.make_mesh((data, model), ("data", "model"), devices=devices)
+    return auto_mesh((data, model), ("data", "model"), devices)

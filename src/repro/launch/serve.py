@@ -13,7 +13,9 @@ before launch so enough virtual devices exist.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,7 @@ from repro.configs import ARCHS
 from repro.core import QuantSpec, quantize_model, run_calibration
 from repro.data.synthetic import DataConfig, SyntheticLM, calibration_batches
 from repro.dist import checkpoint as ckpt
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.registry import build_model
 from repro.obs import Tracer, profile_session
@@ -72,6 +75,64 @@ def parse_at(arg):
     except ValueError:
         raise argparse.ArgumentTypeError(
             f"expected comma-separated ints, got {arg!r}")
+
+
+@dataclasses.dataclass
+class QuantizedModel:
+    """What serving needs once the fp weights are gone: the model, its
+    packed weights, the calibration statistics (the self-int8 draft is
+    built from ``qparams`` and ``stats``) and the synthetic data source
+    requests are drawn from."""
+    model: Any
+    qparams: Any
+    stats: dict
+    data: SyntheticLM
+
+
+def quantize_for_serving(cfg, *, method: str = "faq", bits: int = 4,
+                         calib_n: int = 16, ckpt_dir=None) -> QuantizedModel:
+    """Init (or restore) → calibrate on synthetic data → quantize to the
+    packed serving format.  The fp weights are dropped before returning,
+    so the device holds only the packed tree afterwards."""
+    model = build_model(cfg)
+    # one program: eager init would hold every per-layer weight and its
+    # stacked copy at once
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
+    if ckpt_dir:
+        step = ckpt.latest_step(ckpt_dir)
+        if step is not None:
+            params = ckpt.restore(ckpt_dir, step, {"params": params})["params"]
+            print(f"loaded checkpoint step {step}")
+    data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size))
+    calib = calibration_batches(data, calib_n, 64)
+    stats = run_calibration(model.forward, params,
+                            [{k: jnp.asarray(v) for k, v in b.items()}
+                             for b in calib])
+    qparams, _ = quantize_model(params, model.quant_site_map(), stats,
+                                method=method,
+                                spec=QuantSpec(bits=bits, group_size=64),
+                                mode="packed")
+    del params
+    return QuantizedModel(model=model, qparams=qparams, stats=stats,
+                          data=data)
+
+
+def build_engine(q: QuantizedModel, *, spec_k: int = 0,
+                 draft: str = "self-int8", tiny: bool = True,
+                 **engine_kw) -> ServeEngine:
+    """A :class:`ServeEngine` over ``q``'s packed weights; ``spec_k > 0``
+    adds speculative decoding with the named draft.  ``engine_kw`` goes
+    to the engine unchanged (slots, cache, mesh, overload, tracing)."""
+    spec_cfg = None
+    if spec_k > 0:
+        # the self-draft re-quantizes the *serving* weights at int8 (the
+        # packed codes are all it needs) with the same calibration stats
+        if draft == "self-int8":
+            d = self_int8_draft(q.model, q.qparams, q.stats)
+        else:
+            d = registry_draft(draft, tiny=tiny)
+        spec_cfg = SpecConfig(k=spec_k, draft=d)
+    return ServeEngine(q.model, q.qparams, spec=spec_cfg, **engine_kw)
 
 
 def main():
@@ -162,6 +223,7 @@ def main():
                          "dispatches")
     args = ap.parse_args()
 
+    enable_compile_cache()
     mesh = None
     if args.mesh is not None:
         mesh = make_local_mesh(*args.mesh)
@@ -170,33 +232,8 @@ def main():
               f"devices")
 
     cfg = ARCHS[args.arch].tiny() if args.tiny else ARCHS[args.arch]
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    if args.ckpt_dir:
-        step = ckpt.latest_step(args.ckpt_dir)
-        if step is not None:
-            params = ckpt.restore(args.ckpt_dir, step,
-                                  {"params": params})["params"]
-            print(f"loaded checkpoint step {step}")
-
-    data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size))
-    calib = calibration_batches(data, args.calib_n, 64)
-    stats = run_calibration(model.forward, params,
-                            [{k: jnp.asarray(v) for k, v in b.items()}
-                             for b in calib])
-    qparams, _ = quantize_model(params, model.quant_site_map(), stats,
-                                method=args.method,
-                                spec=QuantSpec(bits=args.bits, group_size=64),
-                                mode="packed")
-    spec_cfg = None
-    if args.spec_k > 0:
-        # the self-draft re-quantizes the *serving* weights at int8 (the
-        # packed codes are all it needs) with the same calibration stats
-        if args.draft == "self-int8":
-            draft = self_int8_draft(model, qparams, stats)
-        else:
-            draft = registry_draft(args.draft, tiny=args.tiny)
-        spec_cfg = SpecConfig(k=args.spec_k, draft=draft)
+    q = quantize_for_serving(cfg, method=args.method, bits=args.bits,
+                             calib_n=args.calib_n, ckpt_dir=args.ckpt_dir)
     slo = None
     if args.deadline_s is not None or args.quota_tokens > 0:
         slo = SLOConfig(margin=args.slo_margin,
@@ -213,20 +250,21 @@ def main():
             stall_at=args.fault_stall_at, stall_s=args.fault_stall_s))
     tracer = (Tracer(capacity=args.trace_capacity)
               if args.trace_out else None)
-    eng = ServeEngine(model, qparams,
-                      n_slots=min(args.n_slots, args.requests),
-                      max_len=args.max_len, paged=args.paged,
-                      page_size=args.page_size, n_pages=args.n_pages,
-                      prefill_chunk=args.prefill_chunk,
-                      spec=spec_cfg, mesh=mesh, slo=slo, faults=faults,
-                      tracer=tracer, profile=bool(args.profile_dir))
+    eng = build_engine(q, spec_k=args.spec_k, draft=args.draft,
+                       tiny=args.tiny,
+                       n_slots=min(args.n_slots, args.requests),
+                       max_len=args.max_len, paged=args.paged,
+                       page_size=args.page_size, n_pages=args.n_pages,
+                       prefill_chunk=args.prefill_chunk, mesh=mesh,
+                       slo=slo, faults=faults, tracer=tracer,
+                       profile=bool(args.profile_dir))
     if args.paged and not eng.paged:
         print("note: model cache layout does not support paging; "
               "serving from the dense cache")
-    if spec_cfg is not None and eng._spec is None:
+    if args.spec_k > 0 and eng._spec is None:
         print("note: model lacks the span-write decode path; serving "
               "non-speculatively")
-    reqs = [Request(rid=i, prompt=data.sequence(40_000_000 + i, 12),
+    reqs = [Request(rid=i, prompt=q.data.sequence(40_000_000 + i, 12),
                     max_new_tokens=args.new_tokens)
             for i in range(args.requests)]
     if args.deadline_s is not None:

@@ -23,6 +23,7 @@ from repro.dist import checkpoint as ckpt
 from repro.dist.elastic import plan_mesh
 from repro.dist.sharding import axis_rules, tree_shardings
 from repro.launch import specs as S
+from repro.launch.mesh import auto_mesh
 from repro.models.registry import build_model
 from repro.train.trainer import TrainConfig, make_train_step
 
@@ -34,8 +35,7 @@ def build_mesh():
     plan = plan_mesh(n, model=min(16, n), old_data=max(1, n // 16))
     import numpy as np
     devices = jax.devices()[:plan.used_chips]
-    return jax.make_mesh((plan.data, plan.model), ("data", "model"),
-                         devices=devices)
+    return auto_mesh((plan.data, plan.model), ("data", "model"), devices)
 
 
 def main():

@@ -26,7 +26,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.core.stats import site_stat
@@ -232,13 +231,13 @@ def moe_ffn(x, router_w, wg, wu, wd, cfg: ModelConfig, collect: bool = False):
     seq_spec = "model" if x.shape[1] % mesh.shape["model"] == 0 else None
     body = functools.partial(_moe_body_sharded, cfg=cfg, model_axis="model",
                              fsdp_axes=fsdp, quantized=quantized)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_spec, seq_spec, None), P(None, None),
                   _expert_specs(wg, 1, fsdp), _expert_specs(wu, 1, fsdp),
                   _expert_specs(wd, 2, fsdp)),
         out_specs=(P(batch_spec, seq_spec, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, router_w, wg, wu, wd)
     return y, aux, {}
 

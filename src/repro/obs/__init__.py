@@ -16,14 +16,14 @@ measurement questions:
 from .metrics import (DEFAULT_MS_EDGES, Counter, Gauge, Histogram,
                       MetricGroup, MetricsRegistry, dist_ms,
                       never_nan_percentile)
-from .profile import annotation, profile_session, profiler_available
+from .profile import annotation, profile_session
 from .trace import (PID_ENGINE, PID_REQUESTS, Tracer, check_span_nesting,
                     validate_trace)
 
 __all__ = [
     "DEFAULT_MS_EDGES", "Counter", "Gauge", "Histogram", "MetricGroup",
     "MetricsRegistry", "dist_ms", "never_nan_percentile",
-    "annotation", "profile_session", "profiler_available",
+    "annotation", "profile_session",
     "PID_ENGINE", "PID_REQUESTS", "Tracer", "check_span_nesting",
     "validate_trace",
 ]

@@ -7,8 +7,8 @@ seam between the two:
 
 * :func:`profile_session` — wrap a serve/bench run in
   ``jax.profiler.trace(logdir)`` (TensorBoard/Perfetto-readable device
-  profile).  ``logdir=None`` or an unavailable profiler degrade to a
-  no-op, so call sites never branch.
+  profile).  ``logdir=None`` degrades to a no-op, so call sites never
+  branch.
 * :func:`annotation` — a named ``TraceAnnotation`` around one jitted
   entry-point call, so prefill/decode/spec dispatches show up as named
   regions inside the device profile.  ``TraceCounter`` applies it when
@@ -20,23 +20,16 @@ attribute check to the hot loop.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
-try:                                     # pragma: no cover - import guard
-    from jax import profiler as _profiler
-except Exception:                        # pragma: no cover
-    _profiler = None
-
-
-def profiler_available() -> bool:
-    return _profiler is not None
+from jax import profiler as _profiler
 
 
 @contextmanager
 def profile_session(logdir=None):
     """Device-profile the enclosed block into ``logdir`` (no-op when
-    ``logdir`` is falsy or jax.profiler is unavailable)."""
-    if not logdir or _profiler is None:
+    ``logdir`` is falsy)."""
+    if not logdir:
         yield None
         return
     with _profiler.trace(str(logdir)):
@@ -44,8 +37,5 @@ def profile_session(logdir=None):
 
 
 def annotation(name: str):
-    """Named profiler region for one dispatch (no-op context manager
-    when the profiler is unavailable)."""
-    if _profiler is None:
-        return nullcontext()
+    """Named profiler region for one dispatch."""
     return _profiler.TraceAnnotation(name)

@@ -10,12 +10,10 @@ verifies them in one batched forward.  Two pluggable sources:
   the target's architecture, cache layout, and KV pages: the draft
   writes its speculative K/V straight into the target cache and the
   verify pass overwrites those positions with target K/V, so the
-  self-draft costs **zero extra KV memory**.  On this CPU reproduction
-  the int8 reconstruction is materialized dense (``mode="fake"``) so
-  draft steps run as plain fp matmuls — cheaper than the target's
-  packed-int4 dequant path; a TPU deployment would keep the int8 codes
-  in HBM (half the weight traffic of fp16) and run them through the
-  same dequant-GEMM kernel as the serving weights.
+  self-draft costs **zero extra KV memory**.  Its weights stay int8
+  codes plus group scales in HBM (half the bytes of bf16; at llama3-8b
+  widths a dense bf16 copy would not fit one 16 GB chip beside the
+  int4 target) and run through the dequant-matmul dispatch.
 
 * :class:`ModelDraft` — any smaller registry model as an independent
   draft with its own small dense KV cache.  Acceptance depends entirely
@@ -26,7 +24,15 @@ verifies them in one batched forward.  Two pluggable sources:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+
+from repro.core import QuantSpec, quantize_model
+from repro.core.apply import _get_path, _set_path
+from repro.core.quantizer import QuantizedTensor, dequantize_groupwise
 
 
 class _Placeable:
@@ -64,37 +70,27 @@ class ModelDraft(_Placeable):
     shares_cache = False
 
 
-def _materialize(qt):
+@functools.partial(jax.jit, static_argnames="dtype")  # repro: noqa[RPR001] build-time weight transform, before any engine or mesh exists
+def _materialize(qt, dtype):
     """Dense original-domain reconstruction of one QuantizedTensor leaf.
 
-    Param-tree leaves carry stacked leading axes (layers, experts); the
-    2-D dequant vmaps over them.  ``act_scale`` is folded back in
-    (``(x/s) @ deq(codes)  ==  x @ (deq(codes) / s[:, None])``), so the
-    result is the exact weight the serving dequant-matmul realizes.
+    Param-tree leaves carry stacked leading axes (layers, experts): the
+    layer axis is walked with ``lax.map``, so one layer's f32 dequant is
+    live at a time, and the rest are vmapped.  ``act_scale`` is folded
+    back in (``(x/s) @ deq(codes)  ==  x @ (deq(codes) / s[:, None])``),
+    so the result is the exact weight the serving dequant-matmul
+    realizes, rounded to ``dtype``.
     """
-    import jax
+    def deq2(sub):
+        w = dequantize_groupwise(dataclasses.replace(sub, act_scale=None))
+        if sub.act_scale is not None:
+            w = w / sub.act_scale[:, None]
+        return w.astype(dtype)
 
-    from repro.core.quantizer import QuantizedTensor, dequantize_groupwise
-
-    def deq2(codes, scale, zero, act):
-        sub = QuantizedTensor(codes=codes, scale=scale, zero=zero,
-                              spec=qt.spec, n_in=qt.n_in, packed=qt.packed,
-                              act_scale=None)
-        w = dequantize_groupwise(sub)
-        if act is not None:
-            w = w / act[:, None]
-        return w
-
-    lead = qt.codes.ndim - 2
-    if qt.act_scale is None:
-        fn = lambda c, s, z: deq2(c, s, z, None)
-        for _ in range(lead):
-            fn = jax.vmap(fn)
-        return fn(qt.codes, qt.scale, qt.zero)
     fn = deq2
-    for _ in range(lead):
+    for _ in range(qt.codes.ndim - 3):
         fn = jax.vmap(fn)
-    return fn(qt.codes, qt.scale, qt.zero, qt.act_scale)
+    return jax.lax.map(fn, qt)
 
 
 def self_int8_draft(model, params, stats=None, *, bits: int = 8,
@@ -109,24 +105,20 @@ def self_int8_draft(model, params, stats=None, *, bits: int = 8,
     the target's almost everywhere, which is what acceptance rate pays
     for.  ``stats`` are the same calibration statistics used to
     quantize the serving weights (FAQ's future-activation preview);
-    without them the draft falls back to plain RTN int8.  The
-    reconstruction is materialized dense (``mode="fake"``) — numerically
-    it *is* the int8 model; see the module docstring for the storage
-    story.
+    without them the draft falls back to plain RTN int8.  One site is
+    materialized at a time, so the peak holds one dense leaf beside the
+    two quantized trees.
     """
-    import jax
-
-    from repro.core import QuantSpec, quantize_model
-    from repro.core.quantizer import QuantizedTensor
-
-    is_qt = lambda x: isinstance(x, QuantizedTensor)
-    params = jax.tree_util.tree_map(
-        lambda x: _materialize(x) if is_qt(x) else x, params, is_leaf=is_qt)
     method = "faq" if stats is not None else "rtn"
-    qp, _ = quantize_model(params, model.quant_site_map(), stats,
-                           method=method,
-                           spec=QuantSpec(bits=bits, group_size=group_size),
-                           mode="fake")
+    spec = QuantSpec(bits=bits, group_size=group_size)
+    dtype = jnp.dtype(model.cfg.dtype)
+    qp = params
+    for path, site in model.quant_site_map().items():
+        leaf = _get_path(qp, path)
+        if isinstance(leaf, QuantizedTensor):
+            qp = _set_path(qp, path, _materialize(leaf, dtype))
+        qp, _ = quantize_model(qp, {path: site}, stats, method=method,
+                               spec=spec, mode="packed")
     return SelfDraft(params=qp, bits=bits)
 
 
@@ -138,8 +130,6 @@ def registry_draft(arch: str, *, tiny: bool = True, seed: int = 0,
     plumbing (greedy output is still exactly the target's; acceptance
     is just poor), real deployments pass trained/distilled weights.
     """
-    import jax
-
     from repro.configs import ARCHS
     from repro.models.registry import build_model
 

@@ -28,14 +28,15 @@ def test_quant_matmul_kernel_vs_oracle(m, k, n, xdtype):
                                rtol=2e-2, atol=2e-2)
 
 
-@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 64)])
+@pytest.mark.parametrize("blocks", [(128, 128), (64, 128), (128, 64)])
 def test_quant_matmul_block_shapes(blocks):
-    bk, bn = blocks
-    w = jax.random.normal(jax.random.PRNGKey(0), (512, 128))
-    x = jax.random.normal(jax.random.PRNGKey(1), (128, 512))
-    spec = QuantSpec(bits=4, group_size=128)
+    """(bm, bn) tiles; K spans several tiles of lcm(8 * g, 256) = 512."""
+    bm, bn = blocks
+    w = jax.random.normal(jax.random.PRNGKey(0), (1536, 128))
+    x = jax.random.normal(jax.random.PRNGKey(1), (128, 1536))
+    spec = QuantSpec(bits=4, group_size=64)
     qt = quantize_groupwise(w, spec, pack=True)
-    out = quant_matmul_pallas(x, qt.codes, qt.scale, qt.zero, bk=bk, bn=bn)
+    out = quant_matmul_pallas(x, qt.codes, qt.scale, qt.zero, bm=bm, bn=bn)
     expect = ref.quant_matmul_ref(x, qt)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-3)
@@ -80,7 +81,8 @@ def test_quant_matmul_kernel_non_tile_shapes(m, k, n, g):
     """Tile-divisibility regression (hymba d_model=1600: 1600 % 128 = 64
     used to trip the kernel's assert; non-multiple-of-128 m tripped the
     dispatch's wrong row padding).  m/n pad to the tile inside the
-    kernel wrapper; k falls back to the group-size tile."""
+    kernel wrapper; a k that no lcm(8 * g, 256) tile divides runs as
+    one K block."""
     w = jax.random.normal(jax.random.PRNGKey(m + n), (k, n))
     x = jax.random.normal(jax.random.PRNGKey(m), (m, k))
     qt = quantize_groupwise(w, QuantSpec(bits=4, group_size=g), pack=True)

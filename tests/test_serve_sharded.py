@@ -236,3 +236,52 @@ print("INVARIANT-OK")
     out = _run(code)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "INVARIANT-OK" in out.stdout
+
+
+def test_kernel_quant_matmul_shard_map():
+    """Kernel mode on a (1, 4) mesh: the Pallas dequant-matmul runs under
+    shard_map (GSPMD cannot partition a Mosaic kernel), column-parallel
+    or — inside row_parallel() — split over input channels with an
+    all-reduce; both equal the single-device jnp reference."""
+    code = """
+import os
+os.environ["REPRO_KERNEL_MODE"] = "interpret"
+import numpy as np
+import jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core import QuantSpec, quantize_groupwise
+from repro.dist.sharding import SERVE_DECODE_RULES, axis_rules, row_parallel
+from repro.kernels import ref
+from repro.kernels.ops import quant_matmul
+from repro.launch.mesh import make_local_mesh
+
+mesh = make_local_mesh(1, 4)
+k, n = 512, 256
+w = jax.random.normal(jax.random.PRNGKey(0), (k, n))
+s = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (k,))) + 0.5
+x = jax.random.normal(jax.random.PRNGKey(2), (6, k))
+qt = quantize_groupwise(w, QuantSpec(bits=4, group_size=64), act_scale=s,
+                        pack=True)
+expect = np.asarray(ref.quant_matmul_ref(x, qt))
+for row in (False, True):
+    spec = P("model", None) if row else P(None, "model")
+    placed = jax.tree_util.tree_map(
+        lambda a: jax.device_put(a, NamedSharding(
+            mesh, spec if a.ndim == 2 else P())), qt)
+
+    def f(x, qt):
+        with axis_rules(mesh, SERVE_DECODE_RULES):
+            if row:
+                with row_parallel():
+                    return quant_matmul(x, qt)
+            return quant_matmul(x, qt)
+
+    lowered = jax.jit(f).lower(x, placed)
+    assert ("all-reduce" in lowered.compile().as_text()) == row
+    got = np.asarray(jax.jit(f)(x, placed))
+    np.testing.assert_allclose(got, expect, atol=1e-3, rtol=1e-3)
+print("SHARD-MAP-OK")
+"""
+    out = _run(code)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "SHARD-MAP-OK" in out.stdout

@@ -82,7 +82,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, dataclasses, functools
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.configs import ARCHS
 from repro.models.moe import (_moe_body_sharded, moe_ffn_local,
                               padded_experts)
@@ -102,11 +101,11 @@ wd = jax.random.normal(ks[4], (e_pad, f, d)) * 0.05
 y_local, _, _ = moe_ffn_local(x, router, wg, wu, wd, cfg)
 body = functools.partial(_moe_body_sharded, cfg=cfg, model_axis="model",
                          fsdp_axes=("data",))
-fn = shard_map(body, mesh=mesh,
+fn = jax.shard_map(body, mesh=mesh,
                in_specs=(P("data", None, None), P(None, None),
                          P("model", "data", None), P("model", "data", None),
                          P("model", None, "data")),
-               out_specs=(P("data", None, None), P()), check_rep=False)
+               out_specs=(P("data", None, None), P()), check_vma=False)
 y_sh, _ = jax.jit(fn)(x, router, wg, wu, wd)
 diff = float(jnp.max(jnp.abs(y_sh - y_local)))
 assert diff < 1e-5, diff
