@@ -9,10 +9,12 @@ from .rpr006_clock_seam import ClockSeamBypass
 from .rpr007_tile_assert import BareTileAssert
 from .rpr008_pool_raise import PoolRaiseInServe
 from .rpr009_obs_bypass import ObsBypassInServe
+from .rpr010_kernel_name import UnnamedPallasCall
 
 RULE_CLASSES = [RawJitInServe, HostSyncInJitted, ScalarArgsWithoutStatic,
                 KernelAccumDtype, SingleServeLoop, ClockSeamBypass,
-                BareTileAssert, PoolRaiseInServe, ObsBypassInServe]
+                BareTileAssert, PoolRaiseInServe, ObsBypassInServe,
+                UnnamedPallasCall]
 
 
 def all_rules():

@@ -135,6 +135,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="prefill_attention",
     )(q, k, v)
     out = out[:, :, :t]
     return out[:, 0] if squeeze else out

@@ -247,6 +247,7 @@ def flash_decode_pallas(q, k_cache, v_cache, cache_len, *, window=None,
         out_shape=_out_shapes(b, kh, ns, g, hd),
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="decode_attention",
     )(lens, qg, k_cache, v_cache)
     return _combine(o, m, l).reshape(b, 1, h, hd).astype(q.dtype)
 
@@ -283,6 +284,7 @@ def flash_decode_q8_pallas(q, k_codes, k_scale, v_codes, v_scale, cache_len,
         out_shape=_out_shapes(b, kh, ns, g, hd),
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="decode_attention",
     )(lens, qg, k_codes, k_scale, v_codes, v_scale)
     return _combine(o, m, l).reshape(b, 1, h, hd).astype(q.dtype)
 
@@ -320,6 +322,7 @@ def flash_decode_paged_pallas(q, k_store, v_store, page_table, cache_len, *,
         out_shape=_out_shapes(b, kh, n_pages, g, hd),
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="decode_attention",
     )(table, lens, qg, k_store, v_store)
     return _combine(o, m, l).reshape(b, 1, h, hd).astype(q.dtype)
 
@@ -359,5 +362,6 @@ def flash_decode_paged_q8_pallas(q, k_codes, k_scale, v_codes, v_scale,
         out_shape=_out_shapes(b, kh, n_pages, g, hd),
         compiler_params=_SEMANTICS,
         interpret=interpret,
+        name="decode_attention",
     )(table, lens, qg, k_codes, k_scale, v_codes, v_scale)
     return _combine(o, m, l).reshape(b, 1, h, hd).astype(q.dtype)

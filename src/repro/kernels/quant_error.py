@@ -101,5 +101,6 @@ def quant_error_pallas(w: jax.Array, scales: jax.Array, mean_sq: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary",                                              "arbitrary")),
         interpret=interpret,
+        name="quant_error",
     )(w, scales, msq2)
     return out[:, 0] / n

@@ -114,5 +114,6 @@ def quant_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dequant_matmul",
     )(x_even, x_odd, codes, scale, zero)
     return out[:m, :n]

@@ -95,6 +95,17 @@ class DenseLM:
         }
 
     # -- block -------------------------------------------------------------
+    def _embed(self, params, tokens):
+        with jax.named_scope("embed"):
+            x = embed_tokens(params["embed"], tokens).astype(self.dtype)
+            return shard_hint(x, "batch", "seq", "embed")
+
+    def _head(self, params, x):
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
+            return logits_from_hidden(x, params["lm_head"],
+                                      self.cfg.vocab_size)
+
     def _attn(self, p, x, positions, *, kv_write=None, cache=None,
               cache_len=None, kv_lens=None, paged=None):
         """Attention sub-block.  Returns (out, (k, v)) — k/v as produced
@@ -130,15 +141,13 @@ class DenseLM:
                 vq, vs = quantize_kv(v)
                 kq, ks = kq.transpose(0, 2, 1, 3), ks.transpose(0, 2, 1, 3)
                 vq, vs = vq.transpose(0, 2, 1, 3), vs.transpose(0, 2, 1, 3)
-                for i in range(t):
-                    k_st = update_pages_at(k_st, kq[:, :, i:i + 1],
-                                           page_ids[:, i], offsets[:, i])
-                    ks_st = update_pages_at(ks_st, ks[:, :, i:i + 1],
-                                            page_ids[:, i], offsets[:, i])
-                    v_st = update_pages_at(v_st, vq[:, :, i:i + 1],
-                                           page_ids[:, i], offsets[:, i])
-                    vs_st = update_pages_at(vs_st, vs[:, :, i:i + 1],
-                                            page_ids[:, i], offsets[:, i])
+                with jax.named_scope("kv_write"):
+                    for i in range(t):
+                        at = (page_ids[:, i], offsets[:, i])
+                        k_st = update_pages_at(k_st, kq[:, :, i:i + 1], *at)
+                        ks_st = update_pages_at(ks_st, ks[:, :, i:i + 1], *at)
+                        v_st = update_pages_at(v_st, vq[:, :, i:i + 1], *at)
+                        vs_st = update_pages_at(vs_st, vs[:, :, i:i + 1], *at)
                 if t == 1:
                     o = paged_decode_attention_q8(q, k_st, ks_st, v_st,
                                                   vs_st, table, cache_len,
@@ -153,11 +162,11 @@ class DenseLM:
                 k_st, v_st = cache
                 kt = k.transpose(0, 2, 1, 3)
                 vt = v.transpose(0, 2, 1, 3)
-                for i in range(t):
-                    k_st = update_pages_at(k_st, kt[:, :, i:i + 1],
-                                           page_ids[:, i], offsets[:, i])
-                    v_st = update_pages_at(v_st, vt[:, :, i:i + 1],
-                                           page_ids[:, i], offsets[:, i])
+                with jax.named_scope("kv_write"):
+                    for i in range(t):
+                        at = (page_ids[:, i], offsets[:, i])
+                        k_st = update_pages_at(k_st, kt[:, :, i:i + 1], *at)
+                        v_st = update_pages_at(v_st, vt[:, :, i:i + 1], *at)
                 if t == 1:
                     o = paged_decode_attention(q, k_st, v_st, table,
                                                cache_len, window=window)
@@ -178,10 +187,13 @@ class DenseLM:
             pos = cache_len - t        # span start; t=1 is plain decode
             kq, ks = quantize_kv(k)
             vq, vs = quantize_kv(v)
-            k_cache = update_cache_at(k_cache, kq.transpose(0, 2, 1, 3), pos)
-            v_cache = update_cache_at(v_cache, vq.transpose(0, 2, 1, 3), pos)
-            k_sc = update_cache_at(k_sc, ks.transpose(0, 2, 1, 3), pos)
-            v_sc = update_cache_at(v_sc, vs.transpose(0, 2, 1, 3), pos)
+            with jax.named_scope("kv_write"):
+                k_cache = update_cache_at(k_cache, kq.transpose(0, 2, 1, 3),
+                                          pos)
+                v_cache = update_cache_at(v_cache, vq.transpose(0, 2, 1, 3),
+                                          pos)
+                k_sc = update_cache_at(k_sc, ks.transpose(0, 2, 1, 3), pos)
+                v_sc = update_cache_at(v_sc, vs.transpose(0, 2, 1, 3), pos)
             window = cfg.sliding_window or None
             if t == 1:
                 o = decode_attention_q8(q, k_cache, k_sc, v_cache, v_sc,
@@ -193,8 +205,11 @@ class DenseLM:
         else:
             k_cache, v_cache = cache  # (B, KH, S, hd)
             pos = cache_len - t           # (B,) span start
-            k_cache = update_cache_at(k_cache, k.transpose(0, 2, 1, 3), pos)
-            v_cache = update_cache_at(v_cache, v.transpose(0, 2, 1, 3), pos)
+            with jax.named_scope("kv_write"):
+                k_cache = update_cache_at(k_cache, k.transpose(0, 2, 1, 3),
+                                          pos)
+                v_cache = update_cache_at(v_cache, v.transpose(0, 2, 1, 3),
+                                          pos)
             window = cfg.sliding_window or None
             if t == 1:
                 o = decode_attention(q, k_cache, v_cache, cache_len,
@@ -210,28 +225,30 @@ class DenseLM:
 
     def _block(self, p, x, positions, collect, *, cache=None, cache_len=None,
                kv_lens=None, paged=None):
-        h = rms_norm(x, p["attn_norm"], self.cfg.norm_eps)
         stats = {}
-        if collect:
-            stats["attn_in"] = site_stat(h)
-        attn_out, kv, o_pre = self._attn(p, h, positions, cache=cache,
-                                         cache_len=cache_len, kv_lens=kv_lens,
-                                         paged=paged)
-        if collect:
-            stats["attn_out"] = site_stat(o_pre)
-        x = x + attn_out
-        h = rms_norm(x, p["mlp_norm"], self.cfg.norm_eps)
-        if collect:
-            stats["mlp_in"] = site_stat(h)
-        g = qlinear(h, p["w_gate"])
-        u = qlinear(h, p["w_up"])
-        hidden = jax.nn.silu(g) * u
-        hidden = shard_hint(hidden, "batch", "seq", "ff")
-        if collect:
-            stats["mlp_down"] = site_stat(hidden)
-        with row_parallel():
-            x = x + qlinear(hidden, p["w_down"])
-        x = shard_hint(x, "batch", "seq", "embed")
+        with jax.named_scope("attn"):
+            h = rms_norm(x, p["attn_norm"], self.cfg.norm_eps)
+            if collect:
+                stats["attn_in"] = site_stat(h)
+            attn_out, kv, o_pre = self._attn(p, h, positions, cache=cache,
+                                             cache_len=cache_len,
+                                             kv_lens=kv_lens, paged=paged)
+            if collect:
+                stats["attn_out"] = site_stat(o_pre)
+            x = x + attn_out
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, p["mlp_norm"], self.cfg.norm_eps)
+            if collect:
+                stats["mlp_in"] = site_stat(h)
+            g = qlinear(h, p["w_gate"])
+            u = qlinear(h, p["w_up"])
+            hidden = jax.nn.silu(g) * u
+            hidden = shard_hint(hidden, "batch", "seq", "ff")
+            if collect:
+                stats["mlp_down"] = site_stat(hidden)
+            with row_parallel():
+                x = x + qlinear(hidden, p["w_down"])
+            x = shard_hint(x, "batch", "seq", "embed")
         return x, kv, stats
 
     # -- entry points --------------------------------------------------------
@@ -243,8 +260,7 @@ class DenseLM:
         tokens = batch["tokens"]
         b, t = tokens.shape
         positions = self._positions(batch, b, t)
-        x = embed_tokens(params["embed"], tokens).astype(self.dtype)
-        x = shard_hint(x, "batch", "seq", "embed")
+        x = self._embed(params, tokens)
 
         def body(x, p):
             x, _, stats = self._block(p, x, positions, collect_stats)
@@ -253,8 +269,7 @@ class DenseLM:
         if self.cfg.remat:
             body = jax.checkpoint(body, prevent_cse=False)
         x, stats = layer_scan(body, x, params["blocks"])
-        x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
-        logits = logits_from_hidden(x, params["lm_head"], self.cfg.vocab_size)
+        logits = self._head(params, x)
         aux = {"stats": stats if collect_stats else {},
                "moe_aux": jnp.zeros((), jnp.float32)}
         return logits, aux
@@ -279,8 +294,7 @@ class DenseLM:
         else:
             plen = jnp.broadcast_to(prompt_len, (b,)).astype(jnp.int32)
             kv_lens = plen
-        x = embed_tokens(params["embed"], tokens).astype(self.dtype)
-        x = shard_hint(x, "batch", "seq", "embed")
+        x = self._embed(params, tokens)
 
         if self.cfg.kv_cache_bits == 8:
             def body8(x, xs):
@@ -289,23 +303,22 @@ class DenseLM:
                                            kv_lens=kv_lens)
                 kq, ks = quantize_kv(k)
                 vq, vs = quantize_kv(v)
-                kc = jax.lax.dynamic_update_slice(
-                    kc, kq.transpose(0, 2, 1, 3), (0, 0, 0, 0))
-                ksc = jax.lax.dynamic_update_slice(
-                    ksc, ks.transpose(0, 2, 1, 3), (0, 0, 0, 0))
-                vc = jax.lax.dynamic_update_slice(
-                    vc, vq.transpose(0, 2, 1, 3), (0, 0, 0, 0))
-                vsc = jax.lax.dynamic_update_slice(
-                    vsc, vs.transpose(0, 2, 1, 3), (0, 0, 0, 0))
+                with jax.named_scope("kv_write"):
+                    kc = jax.lax.dynamic_update_slice(
+                        kc, kq.transpose(0, 2, 1, 3), (0, 0, 0, 0))
+                    ksc = jax.lax.dynamic_update_slice(
+                        ksc, ks.transpose(0, 2, 1, 3), (0, 0, 0, 0))
+                    vc = jax.lax.dynamic_update_slice(
+                        vc, vq.transpose(0, 2, 1, 3), (0, 0, 0, 0))
+                    vsc = jax.lax.dynamic_update_slice(
+                        vsc, vs.transpose(0, 2, 1, 3), (0, 0, 0, 0))
                 return x, (kc, ksc, vc, vsc)
 
             x, (kc, ksc, vc, vsc) = layer_scan(
                 body8, x, (params["blocks"], cache["k"], cache["k_scale"],
                            cache["v"], cache["v_scale"]))
             x = x[:, -1:] if prompt_len is None else last_valid_hidden(x, plen)
-            x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
-            logits = logits_from_hidden(x, params["lm_head"],
-                                        self.cfg.vocab_size)
+            logits = self._head(params, x)
             return logits, {"k": kc, "k_scale": ksc, "v": vc,
                             "v_scale": vsc, "len": plen}
 
@@ -313,17 +326,17 @@ class DenseLM:
             p, kc, vc = xs
             x, (k, v), _ = self._block(p, x, positions, False,
                                        kv_lens=kv_lens)
-            kc = jax.lax.dynamic_update_slice(
-                kc, k.transpose(0, 2, 1, 3), (0, 0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(
-                vc, v.transpose(0, 2, 1, 3), (0, 0, 0, 0))
+            with jax.named_scope("kv_write"):
+                kc = jax.lax.dynamic_update_slice(
+                    kc, k.transpose(0, 2, 1, 3), (0, 0, 0, 0))
+                vc = jax.lax.dynamic_update_slice(
+                    vc, v.transpose(0, 2, 1, 3), (0, 0, 0, 0))
             return x, (kc, vc)
 
         x, (kc, vc) = layer_scan(body, x, (params["blocks"], cache["k"],
                                              cache["v"]))
         x = x[:, -1:] if prompt_len is None else last_valid_hidden(x, plen)
-        x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
-        logits = logits_from_hidden(x, params["lm_head"], self.cfg.vocab_size)
+        logits = self._head(params, x)
         return logits, {"k": kc, "v": vc, "len": plen}
 
     def decode_step(self, params, cache, token, pos=None):
@@ -341,8 +354,7 @@ class DenseLM:
         new_len = base + t
         positions = base[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
         positions = self._maybe_mrope(positions)
-        x = embed_tokens(params["embed"], token).astype(self.dtype)
-        x = shard_hint(x, "batch", "seq", "embed")
+        x = self._embed(params, token)
 
         if self.cfg.kv_cache_bits == 8:
             def body8(x, xs):
@@ -355,9 +367,7 @@ class DenseLM:
             x, (kc, ksc, vc, vsc) = layer_scan(
                 body8, x, (params["blocks"], cache["k"], cache["k_scale"],
                            cache["v"], cache["v_scale"]))
-            x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
-            logits = logits_from_hidden(x, params["lm_head"],
-                                        self.cfg.vocab_size)
+            logits = self._head(params, x)
             return logits, {"k": kc, "k_scale": ksc, "v": vc,
                             "v_scale": vsc, "len": new_len}
 
@@ -369,8 +379,7 @@ class DenseLM:
 
         x, (kc, vc) = layer_scan(body, x, (params["blocks"], cache["k"],
                                              cache["v"]))
-        x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
-        logits = logits_from_hidden(x, params["lm_head"], self.cfg.vocab_size)
+        logits = self._head(params, x)
         return logits, {"k": kc, "v": vc, "len": new_len}
 
     def decode_step_paged(self, params, store, token, page_table, lens):
@@ -397,8 +406,7 @@ class DenseLM:
         page_ids = jnp.take_along_axis(page_table, pos2d // ps, axis=1)
         offsets = pos2d % ps
         paged = (page_table, page_ids, offsets)
-        x = embed_tokens(params["embed"], token).astype(self.dtype)
-        x = shard_hint(x, "batch", "seq", "embed")
+        x = self._embed(params, token)
 
         if self.cfg.kv_cache_bits == 8:
             def body8(x, xs):
@@ -411,9 +419,7 @@ class DenseLM:
             x, (kc, ksc, vc, vsc) = layer_scan(
                 body8, x, (params["blocks"], store["k"], store["k_scale"],
                            store["v"], store["v_scale"]))
-            x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
-            logits = logits_from_hidden(x, params["lm_head"],
-                                        self.cfg.vocab_size)
+            logits = self._head(params, x)
             return logits, {"k": kc, "k_scale": ksc, "v": vc, "v_scale": vsc}
 
         def body(x, xs):
@@ -425,8 +431,7 @@ class DenseLM:
 
         x, (kc, vc) = layer_scan(body, x, (params["blocks"], store["k"],
                                              store["v"]))
-        x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
-        logits = logits_from_hidden(x, params["lm_head"], self.cfg.vocab_size)
+        logits = self._head(params, x)
         return logits, {"k": kc, "v": vc}
 
     # -- speculative verify (DESIGN.md §12) --------------------------------

@@ -104,6 +104,11 @@ class Tracer:
     def thread_name(self, pid: int, tid: int, name: str):
         self._threads[(pid, tid)] = name
 
+    @property
+    def recorded(self) -> int:
+        """Events held in the ring now."""
+        return len(self._events)
+
     def events(self) -> list:
         """Recorded events with timestamps anchored to the *earliest*
         surviving event and converted to microseconds.  Anchoring at
@@ -138,7 +143,7 @@ class Tracer:
                 "displayTimeUnit": "ms",
                 "otherData": {"capacity": self.capacity,
                               "dropped": self.dropped,
-                              "recorded": len(self._events)}}
+                              "recorded": self.recorded}}
 
     def export(self, path) -> str:
         """Write the trace as deterministic JSON (sorted keys, compact

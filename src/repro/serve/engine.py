@@ -95,6 +95,8 @@ class ServeEngine:
         if tracer is not None:
             tracer.clock = self.clock
         self._profile = bool(profile)
+        # the step-loop hooks' one check (instrument.step_span)
+        self._observed = tracer is not None or self._profile
         # overload seams (DESIGN.md §16): slo is an SLOConfig or
         # SLOAdmission (shed gate + tenant quotas), faults a
         # FaultInjector consulted by the pool and the serve loop.  Both
@@ -562,6 +564,12 @@ class ServeEngine:
             with instrument.step_span(self, "sampler_sync"):
                 toks = np.asarray(st.slot_last)  # repro: noqa[RPR002] the designed per-step budget: one int32 per slot for emission
         self._m["decode_steps"] += 1
+        with instrument.step_span(self, "emit"):
+            self._emit_step(run, toks)
+
+    def _emit_step(self, run: ServeRun, toks):
+        """Per-slot emission and finish checks after a plain step."""
+        st = run.st
         now = self.clock()
         for s in range(self.n_slots):
             req = st.req[s]
@@ -596,6 +604,13 @@ class ServeEngine:
             with instrument.step_span(self, "sampler_sync"):
                 last_np = np.asarray(st.slot_last).copy()  # repro: noqa[RPR002] burst emission rewrites slot_last on host; k+1 int32 per slot
         self._m["decode_steps"] += 1
+        with instrument.step_span(self, "emit"):
+            self._emit_burst(run, out, n_acc, last_np)
+
+    def _emit_burst(self, run: ServeRun, out, n_acc, last_np):
+        """Per-slot emission of a speculative burst, finish checks and
+        the rejected-suffix rollback."""
+        st = run.st
         now = self.clock()
         for s in range(self.n_slots):
             req = st.req[s]

@@ -13,9 +13,14 @@ over the engine + request state, same pattern as :mod:`.overload`:
   preemption closes the decode span and restarts the clock, so a
   twice-preempted request renders as three queue/prefill/decode
   triples on one row.
-* **engine step loop** — :func:`step_span` wraps one admit pass,
-  decode step, spec cycle, or sampler sync as an engine-track span and
-  feeds the phase-labeled ``serve.step_ms`` histogram.
+* **engine step loop** — :func:`step_span` wraps one phase of the
+  loop (admit pass, decode step and its ``prepare`` / ``dispatch`` /
+  ``sampler_sync`` parts, spec cycle, ``emit``): it feeds the
+  phase-labeled ``serve.step_ms`` histogram, emits an engine-track span
+  to the tracer, and with ``profile=True`` runs the phase under a
+  ``serve.<phase>`` profiler annotation, on the device trace's clock.
+  :func:`annotate` is that annotation alone (the jitted entry points'
+  ``TraceCounter`` uses it).
 * **pages** — :func:`page_event` marks alloc / copy-on-write / trim /
   pressure instants with a pages-in-use counter track.
 * **metrics digest** — :func:`collect_metrics` is the body of
@@ -27,13 +32,15 @@ over the engine + request state, same pattern as :mod:`.overload`:
 Every timestamp is read through ``eng.clock`` — the injectable seam
 (RPR006) — and nothing here touches device values: tracing adds zero
 host transfers to the serve path (RPR002 + the HLO audit stay clean).
-With ``eng.tracer is None`` every hook is a cheap early return.
+With ``eng.tracer is None`` the lifecycle and page hooks return early;
+an engine with neither a tracer nor ``profile=True`` is unobserved, and
+the step-loop hooks then cost one attribute check.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
-from repro.obs import PID_REQUESTS
+from repro.obs import PID_REQUESTS, annotation
 
 
 # ---------------------------------------------------------------------------
@@ -145,22 +152,33 @@ def settled(eng, req, outcome: str):
 
 @contextmanager
 def step_span(eng, phase: str, **args):
-    """Engine-track span around one step-loop phase (admit pass,
-    decode step, spec cycle, sampler sync); the duration also lands in
-    the phase-labeled ``serve.step_ms`` histogram.  No-op (single
-    attribute check) when the engine has no tracer."""
-    tr = eng.tracer
-    if tr is None:
+    """One step-loop phase: its duration on ``eng.clock`` lands in the
+    phase-labeled ``serve.step_ms`` histogram, on the tracer's engine
+    track (with a tracer) and, under ``profile=True``, in the device
+    trace as the ``serve.<phase>`` annotation.  An unobserved engine
+    pays one attribute check."""
+    if not eng._observed:
         yield args
         return
     t0 = eng.clock()
     try:
-        yield args
+        with annotate(eng, f"serve.{phase}"):
+            yield args
     finally:
         t1 = eng.clock()
-        tr.complete(phase, t0, t1, cat="step", args=args or None)
+        if eng.tracer is not None:
+            eng.tracer.complete(phase, t0, t1, cat="step",
+                                args=args or None)
         eng.registry.histogram("serve.step_ms",
                                phase=phase).observe((t1 - t0) * 1e3)
+
+
+def annotate(eng, name: str):
+    """Named ``jax.profiler`` region when the engine profiles, else a
+    no-op context."""
+    if eng is not None and eng._profile:
+        return annotation(name)
+    return nullcontext()
 
 
 def page_event(eng, kind: str, **args):
@@ -256,7 +274,7 @@ def collect_metrics(eng) -> dict:
     dt = m["serve_time_s"]
     m["tokens_per_s"] = (m["tokens_generated"] / dt) if dt > 0 else 0.0
     if eng.tracer is not None:
-        m["trace"] = dict(events=len(eng.tracer.events()),
+        m["trace"] = dict(events=eng.tracer.recorded,
                           dropped=eng.tracer.dropped,
                           capacity=eng.tracer.capacity)
     return m

@@ -65,6 +65,7 @@ def policy_in_use(top_k, top_p) -> Tuple[bool, bool]:
     return bool((tk > 0).any()), bool(((tp > 0) & (tp < 1)).any())
 
 
+@jax.named_scope("sample")
 def sample_tokens(logits: jax.Array, temperature: jax.Array,
                   top_k: Optional[jax.Array], key: jax.Array,
                   top_p: Optional[jax.Array] = None) -> jax.Array:

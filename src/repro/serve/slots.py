@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs import annotation as obs_annotation
+from . import instrument
 
 
 @dataclasses.dataclass
@@ -71,13 +71,12 @@ class TraceCounter:
     shape/dtype signatures (== XLA traces for a jit with no static
     args).  The serving tests assert prefill traces <= bucket count.
 
-    With a ``name`` and an ``engine``, every *new* signature also lands
-    in the observability layer: a ``compile`` (first trace) or
-    ``retrace`` instant on the engine's tracer and an entry-labeled
-    ``serve.jit_traces`` registry counter — so a recompile mid-traffic
-    shows up as a named event instead of a mystery latency spike.  When
-    the engine was built with ``profile=True`` each dispatch runs under
-    a named ``jax.profiler`` annotation."""
+    With a ``name`` and an ``engine`` that has a tracer, every *new*
+    signature also lands on the trace as a ``compile`` (first trace) or
+    ``retrace`` instant — so a recompile mid-traffic shows up as a named
+    event instead of a mystery latency spike.  When the engine was built
+    with ``profile=True`` each dispatch runs under a ``jax.profiler``
+    annotation of that name (:func:`.instrument.annotate`)."""
 
     def __init__(self, fn, name: Optional[str] = None, engine=None):
         self.fn = fn
@@ -88,10 +87,7 @@ class TraceCounter:
 
     def _on_new_sig(self):
         eng = self.engine
-        if eng is None:
-            return
-        eng.registry.counter("serve.jit_traces", entry=self.name).inc()
-        if eng.tracer is not None:
+        if eng is not None and eng.tracer is not None:
             eng.tracer.instant(
                 "compile" if len(self._sigs) == 1 else "retrace",
                 cat="jit", args=dict(entry=self.name,
@@ -107,10 +103,8 @@ class TraceCounter:
         if sig not in self._sigs:
             self._sigs.add(sig)
             self._on_new_sig()
-        if self.engine is not None and self.engine._profile:
-            with obs_annotation(self.name):
-                return self.fn(*args)
-        return self.fn(*args)
+        with instrument.annotate(self.engine, self.name):
+            return self.fn(*args)
 
     @property
     def traces(self) -> int:

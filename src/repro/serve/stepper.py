@@ -173,20 +173,27 @@ class DenseStepper:
 
     # -- decode-loop entry points --------------------------------------------
     def plain_step(self, st: SlotTable):
+        """One decode step in two traced phases: ``prepare`` builds the
+        host->device arguments, ``dispatch`` is the jitted call."""
         eng = self.engine
+        # outside prepare: with a slot filling its prompt this reads the
+        # sampled tokens on the host, which after an admission waits for
+        # the prefill on the device
         sl = st.input_tokens()
-        if eng._spec is not None:
-            # keep the independent draft's KV aligned through plain
-            # fallback / fill steps (self-draft shares the cache)
-            eng._spec.track_step(
-                jnp.asarray(sl),
-                np.where(st.active, st.slot_len,
-                         np.minimum(st.slot_len, eng.max_len - 1)))
-        st.slot_last, self.cache = self._decode(
-            eng.params, self.cache, jnp.asarray(sl),
-            jnp.asarray(st.active),
-            *eng._policy_args(st.temps, st.top_k, st.top_p),
-            eng._next_key())
+        with instrument.step_span(eng, "prepare"):
+            if eng._spec is not None:
+                # keep the independent draft's KV aligned through plain
+                # fallback / fill steps (self-draft shares the cache)
+                eng._spec.track_step(
+                    jnp.asarray(sl),
+                    np.where(st.active, st.slot_len,
+                             np.minimum(st.slot_len, eng.max_len - 1)))
+            args = (eng.params, self.cache, jnp.asarray(sl),
+                    jnp.asarray(st.active),
+                    *eng._policy_args(st.temps, st.top_k, st.top_p),
+                    eng._next_key())
+        with instrument.step_span(eng, "dispatch"):
+            st.slot_last, self.cache = self._decode(*args)
 
     def spec_cycle(self, st: SlotTable, k_eff: int):
         eng = self.engine
@@ -444,24 +451,32 @@ class PagedStepper(DenseStepper):
 
     # -- decode-loop entry points --------------------------------------------
     def plain_step(self, st: SlotTable):
+        """One decode step: ``prepare`` makes each active slot's next
+        page writable and builds the arguments, ``dispatch`` is the
+        jitted call."""
         eng = self.engine
+        # outside prepare: with a slot filling its prompt this reads the
+        # sampled tokens on the host, which after an admission waits for
+        # the prefill on the device
         sl = st.input_tokens()
-        lens = np.minimum(st.slot_len, eng.max_len - 1)  # retired slots
-        for s in range(eng.n_slots):
-            if not st.active[s]:
-                continue
-            lens[s] = st.slot_len[s]
-            self.ensure_writable(s, int(st.slot_len[s]))
-        if eng._spec is not None:
-            # align the independent draft's KV through fill / fallback
-            # steps (it sees the same token stream)
-            eng._spec.track_step(jnp.asarray(sl), lens)
-        st.slot_last, self.store = self._decode_paged(
-            eng.params, self.store, jnp.asarray(self.table),
-            jnp.asarray(lens.astype(np.int32)), jnp.asarray(sl),
-            jnp.asarray(st.active),
-            *eng._policy_args(st.temps, st.top_k, st.top_p),
-            eng._next_key())
+        with instrument.step_span(eng, "prepare"):
+            lens = np.minimum(st.slot_len, eng.max_len - 1)  # retired slots
+            for s in range(eng.n_slots):
+                if not st.active[s]:
+                    continue
+                lens[s] = st.slot_len[s]
+                self.ensure_writable(s, int(st.slot_len[s]))
+            if eng._spec is not None:
+                # align the independent draft's KV through fill / fallback
+                # steps (it sees the same token stream)
+                eng._spec.track_step(jnp.asarray(sl), lens)
+            args = (eng.params, self.store, jnp.asarray(self.table),
+                    jnp.asarray(lens.astype(np.int32)), jnp.asarray(sl),
+                    jnp.asarray(st.active),
+                    *eng._policy_args(st.temps, st.top_k, st.top_p),
+                    eng._next_key())
+        with instrument.step_span(eng, "dispatch"):
+            st.slot_last, self.store = self._decode_paged(*args)
 
     def spec_cycle(self, st: SlotTable, k_eff: int):
         """Paged speculative cycle: pre-own the burst's pages (alloc /

@@ -155,6 +155,36 @@ def test_rpr007_bare_tile_assert(tmp_path):
     assert [(f.rule, f.line) for f in findings] == [("RPR007", 2)]
 
 
+def test_rpr010_unnamed_pallas_call(tmp_path):
+    findings = lint_snippet(tmp_path, "repro/kernels/k.py", (
+        "from jax.experimental import pallas as pl\n"
+        "\n"
+        "def f(kernel, shape, x):\n"
+        "    return pl.pallas_call(kernel, out_shape=shape)(x)\n"), "RPR010")
+    assert [(f.rule, f.line) for f in findings] == [("RPR010", 4)]
+    # a name, or a **kwargs that may carry one, passes
+    assert not lint_snippet(tmp_path, "repro/kernels/k2.py", (
+        "from jax.experimental import pallas as pl\n"
+        "\n"
+        "def f(kernel, shape, x, **kw):\n"
+        "    a = pl.pallas_call(kernel, out_shape=shape,\n"
+        "                       name='dequant_matmul')(x)\n"
+        "    return pl.pallas_call(kernel, out_shape=shape, **kw)(a)\n"),
+        "RPR010")
+    # kernels/ scope only
+    assert not lint_snippet(tmp_path, "repro/serve/k.py", (
+        "def f(pl, kernel, shape, x):\n"
+        "    return pl.pallas_call(kernel, out_shape=shape)(x)\n"),
+        "RPR010")
+
+
+def test_rpr010_kernels_tree_is_clean():
+    """Every Pallas kernel in the tree states its role by name."""
+    kernels = REPO / "src" / "repro" / "kernels"
+    assert run_lint([str(kernels)], rules_by_code("RPR010"),
+                    base=REPO) == []
+
+
 def test_rpr008_pool_raise_in_serve(tmp_path):
     findings = lint_snippet(tmp_path, "repro/serve/stepper.py", (
         "from .pages import PoolExhausted\n"

@@ -291,6 +291,96 @@ def test_compile_events_and_retrace_by_entry(fp_setup):
     assert any(e["name"] == "compile" for e in jit_events)
     entries = {e["args"]["entry"] for e in jit_events}
     assert "decode" in entries
+
+
+# -- step phases: one helper, three sinks -------------------------------------
+
+PLAIN_STEP_PHASES = ("prepare", "dispatch", "sampler_sync", "emit")
+
+
+def _phase_run(m, params, paged, **kw):
+    eng = ServeEngine(m, params, n_slots=2, max_len=64, paged=paged, **kw)
+    eng.serve([Request(rid=i, prompt=np.arange(1, 6 + i, dtype=np.int32),
+                       max_new_tokens=4) for i in range(3)])
+    return eng
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_plain_step_phases_once_per_step(fp_setup, paged):
+    """prepare, dispatch, sampler_sync and emit each run once per plain
+    decode step, inside decode_step where they belong, on both
+    steppers."""
+    cfg, m, params = fp_setup
+    tracer = Tracer()
+    eng = _phase_run(m, params, paged, clock=_ticker(), tracer=tracer)
+    assert eng.paged == paged
+    steps = eng.metrics()["decode_steps"]
+    assert steps > 0
     snap = eng.registry.snapshot()
-    assert snap["serve.jit_traces{entry=decode}"] \
-        == eng._decode.traces
+    for phase in PLAIN_STEP_PHASES + ("decode_step",):
+        assert snap[f"serve.step_ms{{phase={phase}}}"]["count"] == steps
+    events = tracer.events()
+    spans = [e for e in events if e.get("cat") == "step"]
+    for phase in PLAIN_STEP_PHASES:
+        assert sum(e["name"] == phase for e in spans) == steps
+    assert check_span_nesting(events) == []
+    # prepare and dispatch nest inside decode_step; emit follows it
+    outer = [e for e in spans if e["name"] == "decode_step"]
+    for name in ("prepare", "dispatch"):
+        for e in (e for e in spans if e["name"] == name):
+            assert any(o["ts"] <= e["ts"]
+                       and e["ts"] + e["dur"] <= o["ts"] + o["dur"]
+                       for o in outer)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_unobserved_engine_records_no_step_phases(fp_setup, paged):
+    cfg, m, params = fp_setup
+    eng = _phase_run(m, params, paged)
+    assert eng.metrics()["decode_steps"] > 0
+    assert not any(k.startswith("serve.step_ms")
+                   for k in eng.registry.snapshot())
+
+
+def _host_names(path):
+    from jax.profiler import ProfileData
+
+    files = sorted(path.rglob("*.xplane.pb"))
+    assert files
+    pd = ProfileData.from_file(str(files[-1]))
+    return {ev.name for plane in pd.planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events}
+
+
+@pytest.mark.parametrize("profile", [True, False], ids=["profile", "plain"])
+def test_profile_puts_step_phases_in_the_host_plane(tmp_path, fp_setup,
+                                                    profile):
+    """With profile=True the step phases and the jitted dispatch land in
+    the profiler's host plane as named annotations (and feed the
+    phase histogram without a tracer); with profile=False none do."""
+    cfg, m, params = fp_setup
+    eng = ServeEngine(m, params, n_slots=2, max_len=64, paged=True,
+                      profile=profile)
+    reqs = [Request(rid=0, prompt=np.arange(1, 6, dtype=np.int32),
+                    max_new_tokens=3)]
+    eng.serve(reqs)                       # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.serve([Request(rid=1, prompt=np.arange(1, 6, dtype=np.int32),
+                           max_new_tokens=3)])
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_names(tmp_path)
+    serve_names = {n for n in names if n.startswith("serve.")}
+    if profile:
+        assert {f"serve.{p}" for p in PLAIN_STEP_PHASES
+                + ("admit", "decode_step")} <= serve_names
+        assert "decode_paged" in names
+        assert any(k.startswith("serve.step_ms")
+                   for k in eng.registry.snapshot())
+    else:
+        assert not serve_names
+        assert "decode_paged" not in names
