@@ -153,15 +153,17 @@ def quant_matmul_experts(x: jax.Array, qt: QuantizedTensor) -> jax.Array:
 # points take the caches' *native* layouts — dense (B, KH, S, hd),
 # paged stores (P, KH, ps, hd) — q (B, 1, H, hd), cache_len (B,) int32.
 # Ref mode transposes into the jnp oracles (bit-identical to the
-# pre-kernel call sites); otherwise the split-KV flash-decode Pallas
-# kernels run (interpret off-TPU).
+# pre-kernel call sites); otherwise the flash-decode Pallas kernels run
+# (interpret off-TPU): split-KV over dense caches, one grid step per slot
+# over its live pages for the page store.
 #
 # When a real mesh with a non-trivial "model" axis is active and both
 # head counts divide it, the whole family runs under a head-axis
 # ``shard_map``: each device owns H/m query heads and KH/m KV heads, so
-# split-KV attention and the in-kernel page gather stay device-local and
+# decode attention and the in-kernel page copies stay device-local and
 # the decode step needs no KV-cache collectives at all (attention is
-# exactly parallel over heads — per-head softmax, no cross-head math).
+# exactly parallel over heads — per-head softmax, no cross-head math);
+# a device may hold a single KV head.
 # Otherwise (no mesh, model=1, or non-dividing head counts) the local
 # body runs directly and GSPMD handles whatever layout it was given.
 # ---------------------------------------------------------------------------
@@ -301,9 +303,9 @@ def paged_decode_attention_q8(q, k_codes, k_scale, v_codes, v_scale,
 # shifted-causal over the tail, length-masked below it.  Ref mode runs
 # one fused masked einsum over all T positions (the cycle-cost win: one
 # score/softmax pass per layer instead of T); kernel modes unroll T
-# calls of the same split-KV flash-decode kernel the non-speculative
-# loop runs, each position with its own cache_len — so per mode, verify
-# row i computes exactly what the sequential decode step would.  T is a
+# calls of the same flash-decode kernel the non-speculative loop runs,
+# each position with its own cache_len — so per mode, verify row i
+# computes exactly what the sequential decode step would.  T is a
 # small static K+1, so either form stays one fused XLA program inside
 # the engine's jitted cycle.
 # ---------------------------------------------------------------------------

@@ -251,6 +251,9 @@ class PagedStepper(DenseStepper):
             self._store_axes)
         self.table = np.full((eng.n_slots, self.pages_per_slot),
                              PagePool.TRASH, np.int32)
+        self._window = eng.model.cfg.sliding_window or None
+        self._pages_live = eng.registry.counter("serve.attn_pages_live")
+        self._pages_table = eng.registry.counter("serve.attn_pages_table")
         self._prefill_paged = TraceCounter(
             eng._jit(self._prefill_paged_fn, SERVE_PREFILL_RULES),
             "prefill_paged", eng)
@@ -466,6 +469,7 @@ class PagedStepper(DenseStepper):
                     continue
                 lens[s] = st.slot_len[s]
                 self.ensure_writable(s, int(st.slot_len[s]))
+            self._count_pages(lens)
             if eng._spec is not None:
                 # align the independent draft's KV through fill / fallback
                 # steps (it sees the same token stream)
@@ -477,6 +481,17 @@ class PagedStepper(DenseStepper):
                     eng._next_key())
         with instrument.step_span(eng, "dispatch"):
             st.slot_last, self.store = self._decode_paged(*args)
+
+    def _count_pages(self, lens):
+        """Pages the decode kernel walks this step, against the table it
+        is handed: every slot from the first page its window reaches to
+        the page holding the step's own token (``lens + 1`` positions)."""
+        n = lens.astype(np.int64) + 1
+        live = -(-n // self.page_size)
+        if self._window is not None:
+            live -= np.maximum(n - self._window, 0) // self.page_size
+        self._pages_live.inc(int(live.sum()))
+        self._pages_table.inc(self.table.size)
 
     def spec_cycle(self, st: SlotTable, k_eff: int):
         """Paged speculative cycle: pre-own the burst's pages (alloc /

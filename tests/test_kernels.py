@@ -270,34 +270,84 @@ def test_flash_decode_q8_vs_ref(h, kh, window, monkeypatch):
                                atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("h,kh,hd", [(4, 4, 48), (8, 2, 64)])
-@pytest.mark.parametrize("window", [None, 24])
+# Paged cases: 24 pages of 16 tokens a slot, so the kernel's 256-token
+# blocks (16 pages) split each table in two.  Slot lengths: empty, one
+# token, an exact multiple of the page, one ending mid-way through the
+# second block, and the full table.  Windows: 24 (inside one page or
+# two), 100 (across the block boundary at 256 for the slot of 300; only
+# the second block for the full slot).
+PAGED_PS, PAGED_NP = 16, 24
+PAGED_LENS = (0, 1, 32, 300, PAGED_PS * PAGED_NP)
+
+
+def _paged_inputs(h, kh, hd, seed):
+    """A shuffled page store whose table entries past each slot's live
+    length point at the trash page, which holds garbage: the kernel must
+    neither visit those pages nor let their contents through."""
+    b, s = len(PAGED_LENS), PAGED_PS * PAGED_NP
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (b, 1, h, hd))
+    k = jax.random.normal(ks[1], (b, kh, s, hd))
+    v = jax.random.normal(ks[2], (b, kh, s, hd))
+    lens = jnp.array(PAGED_LENS, jnp.int32)
+    _, _, table, paged = _paged_store(k, v, ps=PAGED_PS, shuffle_seed=seed)
+    live = (np.arange(PAGED_NP)[None] * PAGED_PS
+            < np.asarray(lens)[:, None])
+    table = jnp.where(live, table, 0)
+
+    def store(x, garbage):
+        return paged(x).at[0].set(garbage)
+    return q, k, v, lens, table, store
+
+
+def _expect_paged(expect, lens):
+    """The oracle spreads an empty slot's softmax over every position;
+    the kernel returns zeros there."""
+    return np.where(np.asarray(lens)[:, None, None, None] == 0, 0.0,
+                    np.asarray(expect))
+
+
+@pytest.mark.parametrize("h,kh,hd", [(4, 4, 48), (8, 2, 64), (14, 2, 160),
+                                     (4, 1, 160)])
+@pytest.mark.parametrize("window", [None, 24, 100])
 def test_flash_decode_paged_vs_ref(h, kh, hd, window, monkeypatch):
+    """Cases (4, 4, 48) and (8, 2, 64) as before, G = 7 at hd 160, and a
+    tensor-parallel shard's single kv head."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
-    q, k, v, lens = _decode_inputs(3, h, kh, hd, 128, seed=3)
-    k_st, v_st, table, _ = _paged_store(k, v, ps=16)
+    q, k, v, lens, table, store = _paged_inputs(h, kh, hd, seed=3)
+    k_st, v_st = store(k, 1e3), store(v, -1e3)
     out = ops.paged_decode_attention(q, k_st, v_st, table, lens,
                                      window=window)
     expect = ref.paged_decode_attention_ref(q, k_st, v_st, table, lens,
                                             window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+    np.testing.assert_allclose(np.asarray(out), _expect_paged(expect, lens),
                                atol=2e-5)
 
 
-@pytest.mark.parametrize("window", [None, 24])
-def test_flash_decode_paged_q8_vs_ref(window, monkeypatch):
-    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
-    q, k, v, lens = _decode_inputs(3, 8, 2, 64, 128, seed=4)
+def _paged_q8_check(h, kh, hd, window, seed):
+    q, k, v, lens, table, store = _paged_inputs(h, kh, hd, seed=seed)
     kc, ksc, vc, vsc = _q8_caches(k, v)
-    _, _, table, paged = _paged_store(k, v, ps=16)
-    k_st, ks_st = paged(kc), paged(ksc)
-    v_st, vs_st = paged(vc), paged(vsc)
+    k_st, ks_st = store(kc, 100), store(ksc, 1e3)
+    v_st, vs_st = store(vc, -100), store(vsc, 1e3)
     out = ops.paged_decode_attention_q8(q, k_st, ks_st, v_st, vs_st, table,
                                         lens, window=window)
     expect = ref.paged_decode_attention_q8_ref(q, k_st, ks_st, v_st, vs_st,
                                                table, lens, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+    np.testing.assert_allclose(np.asarray(out), _expect_paged(expect, lens),
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("window", [None, 24, 100])
+def test_flash_decode_paged_q8_vs_ref(window, monkeypatch):
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    _paged_q8_check(8, 2, 64, window, seed=4)
+
+
+@pytest.mark.parametrize("h,kh,hd", [(14, 2, 160), (4, 1, 160)])
+def test_flash_decode_paged_q8_shapes_vs_ref(h, kh, hd, monkeypatch):
+    """The int8 fold at G = 7 and hd 160, and on a single kv head."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    _paged_q8_check(h, kh, hd, 100, seed=6)
 
 
 def test_flash_decode_ref_mode_dispatch(monkeypatch):

@@ -139,6 +139,42 @@ def test_paged_serve_matches_generate_kv8():
     assert eng.metrics()["prefix_hits"] >= 1
 
 
+@pytest.mark.parametrize("window", [0, 12])
+def test_attention_page_counters_match_the_steps(window):
+    """``serve.attn_pages_live`` adds, for every plain decode step, the
+    pages each slot's attention reaches (from the first position its
+    window holds to the step's own token), and ``serve.attn_pages_table``
+    the table the kernel is handed; checked against the lengths the
+    jitted step actually received."""
+    cfg = dataclasses.replace(ARCHS["llama3-8b"].tiny(),
+                              sliding_window=window)
+    m = build_model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    ps = 8
+    eng = ServeEngine(m, params, n_slots=3, max_len=48, paged=True,
+                      page_size=ps)
+    seen = []
+    decode = eng._stepper._decode_paged
+
+    def spy(*args):
+        seen.append((np.asarray(args[3]), args[2].shape))
+        return decode(*args)
+
+    eng._stepper._decode_paged = spy
+    eng.serve(_mixed_shared_requests(cfg, 5, prefix_len=3, seed=2,
+                                     max_new=(4, 12)))
+    live = table = 0
+    for lens, shape in seen:
+        for n in lens + 1:
+            lo = max(n - window, 0) if window else 0
+            live += len({pos // ps for pos in range(lo, n)})
+        table += shape[0] * shape[1]
+    reg = eng.registry.snapshot()
+    assert len(seen) == reg["serve.decode_steps"] > 0
+    assert reg["serve.attn_pages_live"] == live
+    assert reg["serve.attn_pages_table"] == table == len(seen) * 3 * 6
+
+
 def test_prefix_sharing_refcounts_and_skipped_prefill(quantized_setup):
     """Two requests sharing a 2-block prefix must map the same physical
     pages (refcounted: index + both slots) and only the second request's

@@ -100,6 +100,27 @@ def test_flash_decode_compiles(one_chip, variant):
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
 
 
+@pytest.mark.parametrize("q8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("h,kh,hd", [(32, 8, 160), (56, 8, 128)],
+                         ids=["stablelm-12b", "g7"])
+def test_paged_decode_compiles_at_cell_size(one_chip, h, kh, hd, q8):
+    """The paged decode at the offline-batch cell's size: 32 slots, a
+    256-page table over 4000 pages of 16; stablelm-12b's hd 160 (a minor
+    dim Mosaic pads to 256 lanes) and a G = 7 group at hd 128."""
+    b, pages, width = 32, 4000, 256
+    store = (pages, kh, PAGE, hd)
+    q = ((b, 1, h, hd), jnp.bfloat16)
+    tail = [((b, width), jnp.int32), ((b,), jnp.int32)]
+    if q8:
+        sc = ((pages, kh, PAGE, 1), jnp.float32)
+        fn = lambda *a: flash_decode_paged_q8_pallas(*a, interpret=False)
+        shapes = [q, (store, jnp.int8), sc, (store, jnp.int8), sc] + tail
+    else:
+        fn = lambda *a: flash_decode_paged_pallas(*a, interpret=False)
+        shapes = [q, (store, jnp.bfloat16), (store, jnp.bfloat16)] + tail
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
 def test_flash_attention_compiles(one_chip):
     """Prefill attention at T=2048 in the grouped GQA layout."""
     t = 2048
